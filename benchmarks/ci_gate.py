@@ -290,9 +290,13 @@ def sharded_row() -> dict:
     subprocess with forced host devices (``XLA_FLAGS`` must land before
     jax initialises, which this process is long past) and collect its
     gate JSON — bit-parity vs single-host across all four scorer
-    backends, sharded throughput, and the zero-lost-requests count."""
+    backends, sharded throughput, and the zero-lost-requests count.
+
+    The child is a CPU lane by design: on a machine with an accelerator
+    it must not contend for the device this process holds."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     with tempfile.TemporaryDirectory() as tmp:
         gate = os.path.join(tmp, "sharded.json")
         cmd = [sys.executable, os.path.join(HERE, "sharded_bench.py"),

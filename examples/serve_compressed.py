@@ -36,6 +36,7 @@ import os
 import sys
 import tempfile
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,7 +44,7 @@ from repro.data import make_dpr_like_kb
 from repro.retrieval import (IndexSpec, ShardSpec, build_index,
                              load_index_meta, save_index)
 from repro.serve import QueryOptions, RetrievalService
-from repro.utils import human_bytes
+from repro.utils import configure_compilation_cache, human_bytes
 
 
 def serve_tiered(service, idx, tmp, queries):
@@ -83,7 +84,6 @@ def serve_tiered(service, idx, tmp, queries):
 def serve_sharded(service, spec, docs, sample, queries, n_shards, batch, k):
     """Act four: the same KB sharded over the device mesh, hot-swapped in
     behind the same front door — identical results, per-shard rollup."""
-    import jax
     n_dev = jax.device_count()
     if n_shards > n_dev:
         print(f"\nsharded swap skipped: --shards {n_shards} wants more "
@@ -141,6 +141,9 @@ def main(argv=None) -> None:
                          "this many devices (needs XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    configure_compilation_cache()
+    dev = jax.devices()[0]
+    device = f"{dev.platform} {dev.device_kind}"
 
     dim = 245 if args.method == "pca_onebit" else args.dim
     kb = make_dpr_like_kb(n_queries=max(64, args.requests * args.batch),
@@ -222,7 +225,7 @@ def main(argv=None) -> None:
                   f"live=v{table['live']}")
             print(f"  latency p50={stats['p50_ms']:.1f}ms "
                   f"p95={stats['p95_ms']:.1f}ms "
-                  f"p99={stats['p99_ms']:.1f}ms  (CPU host)")
+                  f"p99={stats['p99_ms']:.1f}ms  ({device})")
             print(f"  admission: {stats['pending_queries']} pending, "
                   f"{stats['requests_rejected']} rejected")
 

@@ -22,6 +22,7 @@ their storage compression ratio.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -29,6 +30,7 @@ import numpy as np
 
 from repro.core import quantization as quant
 from repro.core.preprocess import Transform
+from repro.utils import chunked_map
 
 
 class CompressionPipeline:
@@ -43,7 +45,9 @@ class CompressionPipeline:
         for t in self.transforms:
             rng, sub = jax.random.split(rng)
             t.fit(docs, queries, rng=sub)
-            docs = t(docs, "docs")
+            # row chunks: a whole-corpus stage would hold its temporaries
+            # for every row at once
+            docs = chunked_map(functools.partial(t, kind="docs"), docs)
             if queries is not None:
                 queries = t(queries, "queries")
         return self

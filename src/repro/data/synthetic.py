@@ -29,6 +29,8 @@ import functools
 import numpy as np
 import jax.numpy as jnp
 
+from repro.utils import chunked
+
 
 @dataclasses.dataclass
 class KBData:
@@ -40,6 +42,9 @@ class KBData:
     @property
     def dim(self) -> int:
         return int(self.docs.shape[-1])
+
+
+_ROW_BLOCK = 65536
 
 
 @functools.lru_cache(maxsize=8)
@@ -89,8 +94,12 @@ def _cached_kb(n_queries, n_docs, d, seed, r_eff, alpha, query_noise,
     z_art = rng.standard_normal((n_articles, r_eff)).astype(np.float32)
     sig = latent_to_obs(z_art)
     sig_norms = np.linalg.norm(sig, axis=1, keepdims=True)
-    sig = sig / sig_norms * 8.0 \
-        * np.exp(rng.normal(0, 0.05, size=(n_articles, 1))).astype(np.float32)
+    # in place, same operations in the same order as
+    # ``sig / sig_norms * 8.0 * jitter``: at 2.1M docs each temporary is
+    # another 6.45 GB of host memory
+    sig /= sig_norms
+    sig *= 8.0
+    sig *= np.exp(rng.normal(0, 0.05, size=(n_articles, 1))).astype(np.float32)
 
     # --- documents: article signal + isotropic span noise + mean offset +
     #     "style" components.  Style dims are orthogonal to everything a
@@ -99,15 +108,31 @@ def _cached_kb(n_queries, n_docs, d, seed, r_eff, alpha, query_noise,
     #     that collapses raw-L2 retrieval while raw-IP survives (Fig. 1 /
     #     Table 5: DPR-CLS IP 0.609 vs L2 0.240).
     art_of_doc = np.repeat(np.arange(n_articles), spans_per_article)[:n_docs]
-    eps_d = rng.standard_normal((n_docs, d)).astype(np.float32) * doc_noise
+    # drawn in row blocks: the same stream as one (n_docs, d) draw, without
+    # its float64 intermediate
+    eps_d = np.empty((n_docs, d), np.float32)
+    for s, e in chunked(n_docs, _ROW_BLOCK):
+        eps_d[s:e] = rng.standard_normal((e - s, d)).astype(np.float32) \
+            * doc_noise
     n_style = 8
     style_basis = q_full[:, r_eff + 2: r_eff + 2 + n_style]      # (d, 8)
     h = rng.standard_normal((n_docs, n_style)).astype(np.float32) \
         * (style_scale / np.sqrt(n_style))
     s_i = np.exp(rng.normal(0.0, norm_jitter, size=(n_docs, 1))
                  ).astype(np.float32)
-    docs = mu_docs[None, :] + s_i * sig[art_of_doc] \
-        + h @ style_basis.T + eps_d
+    # mu_docs is float64, so each document is summed in float64 and kept as
+    # the float32 that KBData holds; block by block, the corpus never
+    # exists in float64 at once
+    h_style = h @ style_basis.T
+    docs = np.empty((n_docs, d), np.float32)
+    doc_l2, doc_l1 = [], []
+    for s, e in chunked(n_docs, _ROW_BLOCK):
+        block = mu_docs[None, :] + s_i[s:e] * sig[art_of_doc[s:e]] \
+            + h_style[s:e] + eps_d[s:e]
+        doc_l2.append(np.linalg.norm(block, axis=1))
+        doc_l1.append(np.sum(np.abs(block), axis=1))
+        docs[s:e] = block
+    del eps_d, h_style
 
     # --- queries: midpoint of two articles + in-subspace noise, with a
     #     per-query signal strength β (heavy-tailed query difficulty — what
@@ -129,9 +154,9 @@ def _cached_kb(n_queries, n_docs, d, seed, r_eff, alpha, query_noise,
     rel = np.minimum(rel, n_docs - 1).astype(np.int32)
 
     meta = {
-        "doc_l2": float(np.mean(np.linalg.norm(docs, axis=1))),
+        "doc_l2": float(np.mean(np.concatenate(doc_l2))),
         "query_l2": float(np.mean(np.linalg.norm(queries, axis=1))),
-        "doc_l1": float(np.mean(np.sum(np.abs(docs), axis=1))),
+        "doc_l1": float(np.mean(np.concatenate(doc_l1))),
         "query_l1": float(np.mean(np.sum(np.abs(queries), axis=1))),
         "seed": seed, "r_eff": r_eff, "alpha": alpha,
     }

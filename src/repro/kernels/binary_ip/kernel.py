@@ -30,7 +30,8 @@ def _unpack_block(words: jax.Array, d: int) -> jax.Array:
     """(n, d/32) uint32 → (n, d) int8 signs in {−1, +1} (VMEM-local)."""
     shifts = jnp.arange(32, dtype=jnp.uint32)
     bits = (words[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    signs = (bits.astype(jnp.int8) * jnp.int8(2)) - jnp.int8(1)
+    # the affine map runs in int32: Mosaic cannot multiply i8 vectors
+    signs = (bits.astype(jnp.int32) * 2 - 1).astype(jnp.int8)
     return signs.reshape(words.shape[0], d)
 
 

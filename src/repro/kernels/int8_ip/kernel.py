@@ -25,7 +25,9 @@ from repro.utils import cdiv
 
 def _int8_ip_kernel(qs_ref, docs_ref, out_ref):
     qs = qs_ref[...]                                  # (bq, d) bf16 (q·scale)
-    docs = docs_ref[...].astype(jnp.bfloat16)         # (bd, d) uint8 → bf16
+    # uint8 → int32 → bf16: Mosaic has no direct uint8 → bf16 cast, and the
+    # detour is exact (bf16 holds every integer up to 256)
+    docs = docs_ref[...].astype(jnp.int32).astype(jnp.bfloat16)   # (bd, d)
     out_ref[...] = jax.lax.dot_general(
         qs, docs,
         dimension_numbers=(((1,), (1,)), ((), ())),

@@ -46,7 +46,8 @@ def _unpack_signs(words: jax.Array, d: int) -> jax.Array:
     """(n, d/32) uint32 → (n, d) int8 signs in {−1, +1} (VMEM-local)."""
     shifts = jnp.arange(32, dtype=jnp.uint32)
     bits = (words[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    signs = (bits.astype(jnp.int8) * jnp.int8(2)) - jnp.int8(1)
+    # the affine map runs in int32: Mosaic cannot multiply i8 vectors
+    signs = (bits.astype(jnp.int32) * 2 - 1).astype(jnp.int8)
     return signs.reshape(words.shape[0], d)
 
 
@@ -64,7 +65,8 @@ def score_block(qe: jax.Array, block: jax.Array, backend: str) -> jax.Array:
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
     if backend == "int8":
-        docs = block.astype(jnp.bfloat16)          # uint8 codes → bf16
+        # uint8 → int32 → bf16 (exact): Mosaic has no direct uint8 → bf16
+        docs = block.astype(jnp.int32).astype(jnp.bfloat16)
         return jax.lax.dot_general(
             qe, docs,                              # qe = (q ⊙ scale) bf16
             dimension_numbers=(((1,), (1,)), ((), ())),
@@ -94,7 +96,7 @@ def _fused_ivf_kernel(probes_ref, qe_ref, storage_ref, ids_ref, base_ref,
 
     ids = ids_ref[...]                                  # (1, L) int32, −1 pad
     s = score_block(qe_ref[...], storage_ref[0], backend)
-    s = s + base_ref[0, 0]                              # rank-1 corrections
+    s = s + base_ref[0, j]                              # rank-1 corrections
     s = jnp.where(ids >= 0, s, NEG_INF)
     run_v, run_i = merge_topk_block(out_v_ref[...], out_i_ref[...],
                                     s, jnp.where(ids >= 0, ids, -1), k)
@@ -129,28 +131,34 @@ def fused_ivf_topk_pallas(probes: jax.Array, qe: jax.Array,
     dq = qe.shape[-1]
     w = list_storage.shape[-1]
 
+    # Per-query and per-list rows get a unit middle axis so each block's
+    # last two dims equal the array's (Mosaic's (8, 128) tiling rule); the
+    # squeezed leading axis hands the kernel the same 2-D refs.  ``base``
+    # is read as scalars, one SMEM row per query.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_q, nprobe),
         in_specs=[
-            pl.BlockSpec((1, dq), lambda i, j, p: (i, 0)),
+            pl.BlockSpec((pl.squeezed, 1, dq), lambda i, j, p: (i, 0, 0)),
             pl.BlockSpec((1, max_len, w), lambda i, j, p: (p[i, j], 0, 0)),
-            pl.BlockSpec((1, max_len), lambda i, j, p: (p[i, j], 0)),
-            pl.BlockSpec((1, 1), lambda i, j, p: (i, j)),
+            pl.BlockSpec((pl.squeezed, 1, max_len),
+                         lambda i, j, p: (p[i, j], 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, nprobe), lambda i, j, p: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, k_pad), lambda i, j, p: (i, 0)),
-            pl.BlockSpec((1, k_pad), lambda i, j, p: (i, 0)),
+            pl.BlockSpec((pl.squeezed, 1, k_pad), lambda i, j, p: (i, 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, k_pad), lambda i, j, p: (i, 0, 0)),
         ],
     )
     vals, ids = pl.pallas_call(
         functools.partial(_fused_ivf_kernel, k=k, backend=backend),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_q, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((n_q, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((n_q, 1, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((n_q, 1, k_pad), jnp.int32),
         ],
         interpret=interpret,
-    )(probes.astype(jnp.int32), qe, list_storage, list_ids,
-      base.astype(jnp.float32))
-    return vals[:, :k], ids[:, :k]
+    )(probes.astype(jnp.int32), qe[:, None, :], list_storage,
+      list_ids[:, None, :], base.astype(jnp.float32)[:, None, :])
+    return vals[:, 0, :k], ids[:, 0, :k]

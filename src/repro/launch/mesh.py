@@ -20,7 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis: (pod=2, data=16, model=16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def rules_for_mesh(mesh) -> AxisRules:
@@ -30,4 +30,12 @@ def rules_for_mesh(mesh) -> AxisRules:
 def make_test_mesh(n_devices: int = 8, model: int = 2):
     """Small mesh for unit tests (requires forced host devices)."""
     data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(n: int) -> tuple:
+    """``Auto`` axes: ``jax.make_mesh`` defaults to ``Explicit`` ones, on
+    which a contraction over a sharded axis (``x.T @ x`` in the PCA
+    moments) raises instead of letting the compiler insert the reduce."""
+    return (jax.sharding.AxisType.Auto,) * n

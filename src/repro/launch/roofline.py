@@ -144,8 +144,6 @@ def analyze(name: str, mesh_desc: str, chips: int, compiled,
             model_flops: Optional[float] = None) -> RooflineReport:
     """Build a report from a jax compiled executable."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):           # older API returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

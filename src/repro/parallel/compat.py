@@ -1,9 +1,7 @@
-"""jax API compatibility shims.
+"""``shard_map`` with the repo's one setting.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-(and its replication-check kwarg was renamed ``check_rep`` → ``check_vma``)
-across the jax versions this repo supports.  Everything in the repo routes
-through :func:`shard_map` below so the call sites stay version-agnostic.
+Everything in the repo routes through :func:`shard_map` below, so the
+replication-check setting lives in one place.
 """
 
 from __future__ import annotations
@@ -12,19 +10,11 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checks disabled.
+    """``jax.shard_map`` with replication checks disabled.
 
     (The repo's collectives deliberately produce replicated outputs from
     sharded inputs — e.g. top-k merges after an all-gather — which the
-    strict checker rejects; both APIs expose a flag to turn it off.)
+    strict checker rejects.)
     """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -37,6 +37,7 @@ from repro.core.pipeline import CompressionPipeline
 from repro.core.preprocess import Transform
 from repro.core.quantization import FloatCast, Int8Quantizer, OneBitQuantizer
 from repro.retrieval.topk import similarity
+from repro.utils import chunked_map
 
 
 def _resolve_pallas(backend: str) -> bool:
@@ -265,11 +266,20 @@ def get_scorer(name: str, quantizer: Optional[Transform] = None,
 def apply_float_stages(stages, x: jax.Array, kind: str) -> jax.Array:
     """Run docs/queries through a pipeline's float stages (shared by the
     single-host index, the sharded index, and the shadow scorer — one
-    definition so the three paths can never diverge)."""
-    x = jnp.asarray(x)
-    for t in stages:
-        x = t(x, kind)
-    return x
+    definition so the three paths can never diverge).
+
+    Rows go through in chunks: encoding a corpus then holds the stages'
+    input-width temporaries for one chunk, not for every row (at 2.1M
+    768-d docs each whole-corpus temporary is 6.45 GB of a 16 GB device).
+    A host (numpy) corpus moves to the device chunk by chunk.
+    """
+    def run(rows):
+        rows = jnp.asarray(rows)
+        for t in stages:
+            rows = t(rows, kind)
+        return rows
+
+    return chunked_map(run, x)
 
 
 def _factory_for(quantizer: Transform) -> Optional[Callable[..., Scorer]]:

@@ -3,11 +3,34 @@
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 from typing import Any, Callable, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed path, since the path is part of every cache key.
+REPO_COMPILATION_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def configure_compilation_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    this leaves it alone.  Otherwise the cache goes to the repo's
+    ``.jax_cache``.  Entry points that run on the accelerator call this;
+    importing ``repro`` never does, so tests and ahead-of-time compiles
+    neither read nor write the cache.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILATION_CACHE))
+    return str(REPO_COMPILATION_CACHE)
 
 
 def rng_seq(seed: int) -> Iterable[jax.Array]:
@@ -24,17 +47,31 @@ def chunked(n: int, chunk: int) -> Iterable[tuple[int, int]]:
         yield start, min(start + chunk, n)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(out: jax.Array, rows: jax.Array, start) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(out, rows, start, axis=0)
+
+
 def chunked_map(fn: Callable[[jax.Array], jax.Array], x: jax.Array,
                 chunk: int = 65536) -> jax.Array:
     """Apply ``fn`` over the leading axis of ``x`` in chunks and concatenate.
 
     Used for streaming transforms over indexes too large to process at once.
+    Each chunk's result is written in place into one preallocated output,
+    so the device holds the output plus one chunk — a concatenation would
+    hold every chunk and the output at once (at 2.1M 768-d rows, 12 GB of
+    a 16 GB chip).
     """
     n = x.shape[0]
     if n <= chunk:
         return fn(x)
-    outs = [fn(x[s:e]) for s, e in chunked(n, chunk)]
-    return jnp.concatenate(outs, axis=0)
+    out = None
+    for s, e in chunked(n, chunk):
+        rows = fn(x[s:e])
+        if out is None:
+            out = jnp.zeros((n,) + rows.shape[1:], rows.dtype)
+        out = _write_rows(out, rows, s)
+    return out
 
 
 def tree_size_bytes(tree: Any) -> int:

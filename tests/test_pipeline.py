@@ -59,3 +59,24 @@ def test_method_ratios():
     assert method_compression_ratio("onebit", 128) == pytest.approx(32.0)
     assert method_compression_ratio(
         "pca_onebit", 245) == pytest.approx(100.0, rel=0.01)
+
+
+@pytest.mark.parametrize("chunk", [16, 70, 500])
+def test_chunked_map_matches_whole_array(kb, chunk):
+    """Chunked application (how a corpus too large for one pass goes
+    through a stage) is the whole-array result, chunk size notwithstanding,
+    from host or device input."""
+    from repro.utils import chunked_map
+    pipe = CompressionPipeline([CenterNorm(), PCA(16), Int8Quantizer()])
+    pipe.fit(kb.docs[:500], kb.queries)
+    docs = np.asarray(kb.docs[:500])
+    stage = pipe.transforms[0]
+    whole = stage(jnp.asarray(docs), "docs")
+    for x in (docs, jnp.asarray(docs)):
+        out = chunked_map(lambda rows: stage(rows, "docs"), x, chunk)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(whole))
+    codes = pipe.transforms[2].encode(pipe.transforms[1](whole), "docs")
+    out = chunked_map(lambda rows: pipe.transforms[2].encode(
+        pipe.transforms[1](rows), "docs"), whole, chunk)
+    assert out.dtype == codes.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(codes))

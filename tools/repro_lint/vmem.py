@@ -19,6 +19,8 @@ Rules:
 * ``vmem-misaligned`` — a block dim is neither a multiple of its lane/sublane
   requirement, nor full-span (block dim == array dim — the compiler pads the
   whole array once), nor 1;
+* SMEM blocks (``memory_space=pltpu.SMEM``) hold scalars, not VMEM: they
+  count 0 bytes but are still held to the tiling rule, as Mosaic holds them;
 * ``vmem-uneval`` — a block shape could not be evaluated (the profile is
   missing a symbol).  Unevaluated specs would silently undercount residency,
   so they are findings, not skips.
@@ -83,15 +85,15 @@ DEFAULT_PROFILES: dict[str, list[KernelProfile]] = {
             "float", {"dq": 768, "w": 768, "max_len": 2048, "k": 10,
                       "nprobe": 8, "n_q": 64},
             ["float32", "float32", "int32", "float32", "float32", "int32"],
-            [(64, 768), (1024, 2048, 768), (1024, 2048), (64, 8),
-             (64, 128), (64, 128)],
+            [(64, 1, 768), (1024, 2048, 768), (1024, 1, 2048), (64, 1, 8),
+             (64, 1, 128), (64, 1, 128)],
         ),
         KernelProfile(
             "onebit", {"dq": 768, "w": 24, "max_len": 2048, "k": 10,
                        "nprobe": 8, "n_q": 64},
             ["int8", "uint32", "int32", "float32", "float32", "int32"],
-            [(64, 768), (1024, 2048, 24), (1024, 2048), (64, 8),
-             (64, 128), (64, 128)],
+            [(64, 1, 768), (1024, 2048, 24), (1024, 1, 2048), (64, 1, 8),
+             (64, 1, 128), (64, 1, 128)],
         ),
     ],
 }
@@ -102,6 +104,8 @@ DEFAULT_PROFILES: dict[str, list[KernelProfile]] = {
 def _eval(node: ast.expr, env: dict[str, int]) -> int | None:
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return node.value
+    if isinstance(node, ast.Attribute) and node.attr == "squeezed":
+        return 1  # ``pl.squeezed``: a unit block axis the kernel never sees
     if isinstance(node, ast.Name):
         return env.get(node.id)
     if isinstance(node, ast.UnaryOp) and isinstance(node.op, ast.USub):
@@ -200,6 +204,11 @@ def _index_map_varies(spec_call: ast.Call) -> bool:
     body = lam.body
     elts = body.elts if isinstance(body, ast.Tuple) else [body]
     return any(not isinstance(e, ast.Constant) for e in elts)
+
+
+def _in_smem(spec_call: ast.Call) -> bool:
+    return any(kw.arg == "memory_space" and isinstance(kw.value, ast.Attribute)
+               and kw.value.attr == "SMEM" for kw in spec_call.keywords)
 
 
 def _spec_shape(spec_call: ast.Call, env: dict[str, int]) -> tuple[int, ...] | None:
@@ -323,7 +332,8 @@ def estimate_file(tree: ast.Module, relpath: str,
                         ests.append(SpecEstimate(f"{side}[{i}]", None, dtype,
                                                  varies, 0, []))
                         continue
-                    nbytes = _padded_bytes(shape, dtype) * (2 if varies else 1)
+                    nbytes = (0 if _in_smem(spec) else
+                              _padded_bytes(shape, dtype) * (2 if varies else 1))
                     ests.append(SpecEstimate(
                         f"{side}[{i}]", shape, dtype, varies, nbytes,
                         _alignment_errors(shape, dtype, array)))
