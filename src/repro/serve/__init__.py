@@ -25,6 +25,10 @@ execution cores.
   invalidation on live updates / compaction / promote.
 * :class:`~repro.serve.batcher.AdaptiveBatcher` — queue-depth-driven
   micro-batch sizing (small batches at low load, wide at saturation).
+* :class:`~repro.serve.trace.Recorder` — spans (``repro.*``, on the
+  profiler's clock) and per-batch / per-request records of the drain
+  path, plus garbage-collection pauses; one per process
+  (:func:`~repro.serve.trace.default_recorder`).
 """
 
 from repro.serve.batcher import AdaptiveBatcher, MicroBatch, MicroBatcher
@@ -40,6 +44,7 @@ from repro.serve.service import (CanaryFailed, QueryHandle, QueryOptions,
 from repro.serve.shadow import ShadowScorer
 from repro.serve.stats import (IndexStats, ServiceStats, ShardStats,
                                VersionStats)
+from repro.serve.trace import Recorder, default_recorder
 
 __all__ = [
     "AdaptiveBatcher", "MicroBatch", "MicroBatcher",
@@ -50,4 +55,5 @@ __all__ = [
     "RetrievalService", "QueryOptions", "QueryHandle",
     "QueueFull", "RateLimited", "CanaryFailed", "ServiceClosed",
     "ServiceStats", "IndexStats", "VersionStats", "ShardStats",
+    "Recorder", "default_recorder",
 ]

@@ -40,6 +40,7 @@ import numpy as np
 from repro.serve.batcher import MicroBatcher
 from repro.serve.metrics import LatencyStats
 from repro.serve.shadow import ShadowScorer
+from repro.serve.trace import default_recorder
 
 
 @dataclasses.dataclass
@@ -48,6 +49,9 @@ class ServeResult:
     scores: np.ndarray           # (n, k)
     ids: np.ndarray              # (n, k)
     latency_s: float             # queue-entry → this request's last batch done
+    admitted_s: Optional[float] = None    # perf_counter stamps: queue entry,
+    dispatched_s: Optional[float] = None  # its first batch's dispatch,
+    done_s: Optional[float] = None        # its last batch's fetch end
 
 
 class ServeEngine:
@@ -65,7 +69,10 @@ class ServeEngine:
         self.k = k
         self.batcher = batcher if batcher is not None else MicroBatcher()
         self.shadow = shadow
-        self.latency = LatencyStats()          # per micro-batch device time
+        self.recorder = default_recorder()
+        # per micro-batch host clock around index.search and the blocking
+        # copy: dispatch start → fetch end
+        self.latency = LatencyStats()
         self.request_latency = LatencyStats()  # per-request queue → done
         # one lock guards the queue AND every counter below: submit,
         # drain's counter updates, and stats() snapshots all take it, so a
@@ -80,6 +87,7 @@ class ServeEngine:
         self._observers: list[ShadowScorer] = []
         self.queries_served = 0
         self.batches_served = 0
+        self.rows_padded = 0                   # dispatched pad rows
         self.requests_served = 0
         self.requests_submitted = 0
         self.queries_submitted = 0
@@ -176,20 +184,45 @@ class ServeEngine:
         moment the micro-batch carrying its *last* rows finishes, not when
         the whole drain does: requests answered by the first batch are
         never charged for later, unrelated batches in the same drain.
+
+        Each step is a span of the process's recorder
+        (:mod:`repro.serve.trace`): ``form`` (pop and form every batch),
+        then per batch ``dispatch`` (``index.search`` until it returns),
+        ``device_wait`` (the recorder's ``flush``, then the copy of the
+        scores, which waits for the device), ``fetch`` (the copy of the
+        ids) and ``scatter`` (observers, per-request results, counters),
+        and one batch record.
         """
         with self._lock:
             if not self._pending:
                 return {}
-            pending, self._pending = self._pending, []
-            submit_time = {rid: self._submit_time.pop(rid)
-                           for rid, _, _, _ in pending}
-            self._inflight_requests += len(pending)
-            self._inflight_rows += sum(q.shape[0] for _, q, _, _ in pending)
-            observers = tuple(([self.shadow] if self.shadow is not None
-                               else []) + self._observers)
-            inflight_rows = self._inflight_rows
-        if hasattr(self.batcher, "observe_depth"):   # adaptive sizing hook
-            self.batcher.observe_depth(inflight_rows)
+        rec = self.recorder
+        with rec.span("form") as form:
+            with self._lock:
+                pending, self._pending = self._pending, []
+                submit_time = {rid: self._submit_time.pop(rid)
+                               for rid, _, _, _ in pending}
+                self._inflight_requests += len(pending)
+                self._inflight_rows += sum(q.shape[0]
+                                           for _, q, _, _ in pending)
+                observers = tuple(([self.shadow] if self.shadow is not None
+                                   else []) + self._observers)
+                inflight_rows = self._inflight_rows
+            if hasattr(self.batcher, "observe_depth"):  # adaptive sizing
+                self.batcher.observe_depth(inflight_rows)
+            # micro-batch per (k, nprobe) group: one compiled graph per
+            # batch.  FIFO order is preserved within each group.
+            groups: dict[tuple[int, Optional[int]],
+                         list[tuple[int, np.ndarray]]] = {}
+            for rid, q, k, nprobe in pending:
+                key = (self.k if k is None else k, nprobe)
+                groups.setdefault(key, []).append((rid, q))
+            formed = [(k, {} if nprobe is None else {"nprobe": nprobe},
+                       batch, rec.next_batch())
+                      for (k, nprobe), items in groups.items()
+                      for batch in self.batcher.form(items)]
+            if formed:
+                form.batch = formed[0][3]
         out_scores: dict[int, np.ndarray] = {}
         out_ids: dict[int, np.ndarray] = {}
         rows_left: dict[int, int] = {}
@@ -199,28 +232,29 @@ class ServeEngine:
             out_ids[rid] = np.empty((n, 0), np.int32)
             rows_left[rid] = n
 
-        # micro-batch per (k, nprobe) group: one compiled graph per batch.
-        # FIFO order is preserved within each group.
-        groups: dict[tuple[int, Optional[int]],
-                     list[tuple[int, np.ndarray]]] = {}
-        for rid, q, k, nprobe in pending:
-            key = (self.k if k is None else k, nprobe)
-            groups.setdefault(key, []).append((rid, q))
-
         results: dict[int, ServeResult] = {}
-        for (k, nprobe), items in groups.items():
-            kwargs = {} if nprobe is None else {"nprobe": nprobe}
-            for batch in self.batcher.form(items):
-                t0 = time.perf_counter()
+        dispatched: dict[int, float] = {}
+        for k, kwargs, batch, bid in formed:
+            with rec.span("dispatch", bid) as dispatch:
                 vals, ids = self.index.search(batch.queries, k, **kwargs)
-                vals, ids = np.asarray(vals), np.asarray(ids)   # blocks
-                done = time.perf_counter()
+            # the first copy waits for the device; a separate
+            # block_until_ready would cost one more host round trip
+            with rec.span("device_wait", bid) as wait:
+                # the recorder's rows go to its rings while the device runs
+                with rec.span("flush", bid):
+                    rec.flush()
+                vals = np.asarray(vals)
+            with rec.span("fetch", bid) as fetch:
+                ids = np.asarray(ids)
+            done = fetch.end
+            with rec.span("scatter", bid):
                 for obs in observers:
                     obs.observe(batch.queries[:batch.n_valid],
                                 ids[:batch.n_valid], k)
                 finished: list[int] = []
                 for s in batch.slices:
                     rid, rows = s.request_id, s.stop - s.start
+                    dispatched.setdefault(rid, dispatch.start)
                     if out_scores[rid].shape[1] == 0:
                         k_out = vals.shape[1]
                         out_scores[rid] = np.empty(
@@ -238,16 +272,23 @@ class ServeEngine:
                     results[rid] = ServeResult(
                         request_id=rid, scores=out_scores[rid],
                         ids=out_ids[rid],
-                        latency_s=done - submit_time[rid])
+                        latency_s=done - submit_time[rid],
+                        admitted_s=submit_time[rid],
+                        dispatched_s=dispatched[rid], done_s=done)
+                padded = batch.queries.shape[0]
                 with self._lock:
-                    self.latency.record(done - t0)
+                    self.latency.record(fetch.end - dispatch.start)
                     self.batches_served += 1
                     self.queries_served += batch.n_valid
+                    self.rows_padded += padded - batch.n_valid
                     self.requests_served += len(finished)
                     self._inflight_requests -= len(finished)
                     for rid in finished:
                         self._inflight_rows -= out_ids[rid].shape[0]
                         self.request_latency.record(results[rid].latency_s)
+            rec.batch(bid, form.parent, batch.n_valid, padded,
+                      min(submit_time[s.request_id] for s in batch.slices),
+                      form, dispatch, wait, fetch)
         return results
 
     # -- observability -----------------------------------------------------
@@ -257,12 +298,15 @@ class ServeEngine:
         ``requests_submitted == requests_served + pending_requests +
         inflight_requests`` holds on *every* snapshot, not just at
         quiesce.  Latency keys (``count``/``p50_ms``/…) are the per-batch
-        device time; ``request_*`` keys are per-request queue-entry →
-        last-batch-done."""
+        host clock around ``index.search`` and the blocking copy;
+        ``request_*`` keys are per-request queue-entry → last-batch-done.
+        ``rows_padded`` counts the pad rows dispatched beyond the valid
+        ones."""
         with self._lock:
             s = {"requests_served": self.requests_served,
                  "queries_served": self.queries_served,
                  "batches_served": self.batches_served,
+                 "rows_padded": self.rows_padded,
                  "requests_submitted": self.requests_submitted,
                  "queries_submitted": self.queries_submitted,
                  "pending_requests": len(self._pending),

@@ -60,13 +60,14 @@ import numpy as np
 from repro.retrieval.segments import SegmentedIndex
 from repro.serve.batcher import MicroBatcher
 from repro.serve.cache import ResultCache
-from repro.serve.engine import ServeResult
+from repro.serve.engine import ServeEngine, ServeResult
 from repro.serve.limits import RateLimiter
 from repro.serve.metrics import LatencyStats
 from repro.serve.router import IndexEntry, IndexRegistry, IndexVersion
 from repro.serve.shadow import ShadowScorer
 from repro.serve.stats import (IndexStats, ServiceStats, ShardStats,
                                VersionStats)
+from repro.serve.trace import default_recorder
 
 
 class QueueFull(RuntimeError):
@@ -178,7 +179,9 @@ class RetrievalService:
         micro-batch sizing); ``cache_rows > 0`` enables the hot-query
         result cache (:mod:`repro.serve.cache`) bounded to that many row
         entries; ``limiter`` installs per-index rate-limit policies (or
-        use :meth:`set_rate_limit`)."""
+        use :meth:`set_rate_limit`).  The serving path's spans and
+        records go to the process-wide
+        :func:`~repro.serve.trace.default_recorder`."""
         self.default_k = default_k
         self.max_pending_queries = max_pending_queries
         self._batcher = batcher if batcher is not None \
@@ -198,6 +201,9 @@ class RetrievalService:
         self.cache_hits = 0
         self.updates_applied = 0
         self.compactions_run = 0
+        self._recorder = default_recorder()
+        self.drain_cycles = 0               # drain_once calls that found work
+        self.poll_timeouts = 0              # idle polls that timed out
         self._poll_interval_s = poll_interval_s
         self._kick = threading.Event()
         self._stop = threading.Event()
@@ -309,6 +315,15 @@ class RetrievalService:
         with self._lock:
             return self._registry.names()
 
+    def engine(self, name: str) -> Optional[ServeEngine]:
+        """The live version's engine of index ``name`` (``None`` while
+        that version is still lazy): its ``stats()`` counters, ``latency``
+        samples and ``recorder`` for operators.  Raises ``KeyError`` for
+        an unregistered index."""
+        with self._lock:
+            version = self._registry.get(name).live_version()
+        return version.engine
+
     # -- rate limiting -----------------------------------------------------
     def set_rate_limit(self, name: str, *, qps: float,
                        burst: Optional[float] = None,
@@ -354,6 +369,13 @@ class RetrievalService:
                              f"shape {np.shape(queries)}")
         n = int(q.shape[0])
 
+        with self._recorder.span("admit"):
+            return self._admit(q, n, options)
+
+    def _admit(self, q: np.ndarray, n: int,
+               options: QueryOptions) -> QueryHandle:
+        """:meth:`query` past validation: bind, cache, rate limit,
+        admission, ``engine.submit``, wake the drain loop."""
         with self._lock:
             self._check_open_locked()
             entry = self._registry.get(options.index)
@@ -435,7 +457,11 @@ class RetrievalService:
     def _run(self) -> None:
         while not self._stop.is_set():
             if not self.drain_once():
-                self._kick.wait(self._poll_interval_s)
+                with self._recorder.span("poll_wait"):
+                    kicked = self._kick.wait(self._poll_interval_s)
+                if not kicked:
+                    with self._admission:
+                        self.poll_timeouts += 1
                 self._kick.clear()
 
     def drain_once(self) -> int:
@@ -446,20 +472,32 @@ class RetrievalService:
         manual dispatch step.
         """
         with self._lock:
-            work = [(entry, iv) for entry in self._registry.entries()
+            work = [iv for entry in self._registry.entries()
                     for iv in list(entry.versions.values()) if iv.loaded]
+        busy = [iv for iv in work if iv.engine.pending]
         resolved = 0
-        for _entry, iv in work:
-            engine = iv.engine
-            if engine.pending == 0:
-                continue
-            try:
-                results = engine.drain()
-            except Exception as e:
-                self._fail_version(iv, e)
-                continue
-            if not results:
-                continue
+        if busy:
+            with self._recorder.span("drain"):
+                for iv in busy:
+                    resolved += self._drain_version(iv)
+            with self._admission:
+                self.drain_cycles += 1
+        self._gc()
+        return resolved
+
+    def _drain_version(self, iv: IndexVersion) -> int:
+        """Drain one engine and resolve its handles (a ``resolve`` span):
+        pop the handles, release admission, fill the result cache, and
+        write each request's record."""
+        try:
+            results = iv.engine.drain()
+        except Exception as e:
+            self._fail_version(iv, e)
+            return 0
+        if not results:
+            return 0
+        rec = self._recorder
+        with rec.span("resolve"):
             with iv.lock:
                 handles = {rid: iv.handles.pop(rid) for rid in results
                            if rid in iv.handles}
@@ -475,10 +513,11 @@ class RetrievalService:
                         # update landed since, these rows are already
                         # unreachable — the insert is harmlessly stale
                         self._cache.put(h._cache_keys, res.scores, res.ids)
+                    rec.request(rid, h.n_rows, res.admitted_s,
+                                res.dispatched_s, res.done_s,
+                                time.perf_counter())
                     h._resolve(res)
-            resolved += len(handles)
-        self._gc()
-        return resolved
+        return len(handles)
 
     def _fail_version(self, iv: IndexVersion, error: Exception) -> None:
         """A drain blew up: every outstanding request on that version was
@@ -755,7 +794,8 @@ class RetrievalService:
         rolled-up totals and merged latency percentiles across every
         engine, as :class:`~repro.serve.stats.ServiceStats`.
 
-        ``latency`` holds the per-batch device-time summary;
+        ``latency`` holds the per-batch summary of the host clock around
+        ``index.search`` and the blocking copy;
         ``request_latency`` the per-request queue-entry → last-batch-done
         summary — the number an SLO is written against.
         ``queue_depth``/``queue_high_water``/``shed_rate`` are the
@@ -764,6 +804,10 @@ class RetrievalService:
         (admission bound + rate limit) over the service's lifetime.
         Versions serving a sharded index additionally carry a per-shard
         rollup (:class:`~repro.serve.stats.ShardStats`).
+        ``drain_cycles`` / ``poll_timeouts`` count the drain loop's
+        cycles that found work and its idle polls that timed out;
+        ``gc_collections`` / ``gc_pause_s`` are the process's garbage
+        collections and pause seconds by generation, from the recorder.
         """
         with self._lock:
             snapshot = [(entry.name, entry.live, entry.staged,
@@ -825,6 +869,9 @@ class RetrievalService:
             rejected = self.requests_rejected
             rate_limited = self.requests_rate_limited
             cache_hits = self.cache_hits
+            drain_cycles = self.drain_cycles
+            poll_timeouts = self.poll_timeouts
+        gc_collections, gc_pause_s = self._recorder.gc_counts()
         with self._update_lock:
             updates_applied = self.updates_applied
             compactions_run = self.compactions_run
@@ -849,6 +896,10 @@ class RetrievalService:
                 request_latencies).summary(),
             cache=self._cache.stats() if self._cache is not None else None,
             limits=limits if limits else None,
+            drain_cycles=drain_cycles,
+            poll_timeouts=poll_timeouts,
+            gc_collections=list(gc_collections),
+            gc_pause_s=list(gc_pause_s),
         )
 
     def stats(self) -> dict:

@@ -104,11 +104,15 @@ class ServiceStats:
     """The full service snapshot :meth:`RetrievalService.stats_typed`
     returns.
 
-    ``latency`` is the merged per-batch device-time summary;
+    ``latency`` is the merged per-batch summary of the host clock around
+    ``index.search`` and the blocking copy;
     ``request_latency`` the per-request queue-entry → last-batch-done
     summary (the SLO numbers).  ``to_dict()`` flattens both into the
     historical top-level keys (``p50_ms``…, ``request_p50_ms``…) so
-    existing readers keep working unchanged.
+    existing readers keep working unchanged.  ``drain_cycles`` /
+    ``poll_timeouts`` are the drain loop's working cycles and timed-out
+    idle polls; ``gc_collections`` / ``gc_pause_s`` the process's garbage
+    collections and their pause seconds by generation (0, 1, 2).
     """
 
     indexes: dict                           # name -> IndexStats
@@ -127,6 +131,12 @@ class ServiceStats:
     request_latency: dict
     cache: Optional[dict] = None
     limits: Optional[dict] = None
+    drain_cycles: int = 0
+    poll_timeouts: int = 0
+    gc_collections: list = dataclasses.field(
+        default_factory=lambda: [0, 0, 0])      # by generation 0, 1, 2
+    gc_pause_s: list = dataclasses.field(
+        default_factory=lambda: [0.0, 0.0, 0.0])
 
     def to_dict(self) -> dict:
         out = {"indexes": {name: ix.to_dict()
@@ -141,6 +151,10 @@ class ServiceStats:
                "cache_hits": self.cache_hits,
                "updates_applied": self.updates_applied,
                "compactions_run": self.compactions_run,
+               "drain_cycles": self.drain_cycles,
+               "poll_timeouts": self.poll_timeouts,
+               "gc_collections": list(self.gc_collections),
+               "gc_pause_s": list(self.gc_pause_s),
                **self.totals,
                **self.latency}
         out.update({f"request_{key}": val
