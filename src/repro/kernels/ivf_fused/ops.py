@@ -5,8 +5,9 @@ list-major storage prepared once by :class:`repro.retrieval.ivf.IVFIndex`)
 and dispatches to the Pallas kernel (interpret mode off-TPU) or the jnp
 reference mirror.  Score corrections that are affine in the query — int8's
 ``q·zero`` dequant term, residual encoding's routed ``q·centroid`` term —
-are folded into the per-(query, probe) ``base`` matrix so the kernel only
-ever adds one scalar per block.
+are folded into the per-(query, probe) ``base`` matrix, which the kernel
+scatters into its dense (Q, nlist) correction matrix: one column add per
+scored tile applies them and masks the rows that did not probe the list.
 """
 
 from __future__ import annotations

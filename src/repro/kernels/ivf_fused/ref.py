@@ -1,11 +1,14 @@
 """Pure-jnp reference for the fused IVF kernel — bitwise oracle.
 
-Runs the *same* per-block score math (``kernel.score_block``) and the same
-list-by-list streaming merge, but expressed as a ``lax.scan`` over probe
-slots with the shared :func:`~repro.retrieval.topk.masked_topk_by_id`
-merge.  Because (score desc, id asc) is a strict total order the two merge
-formulations are equivalent, so the parity tests can demand exact id *and*
-value equality against the interpret-mode kernel.
+Runs the *same* per-tile score math as the kernel (``kernel.score_block``
+on the same (Q_pad, dq) × (Lc, w) shapes, chunk-major over the lists in
+ascending order), but takes each list's correction straight from the
+probe table rather than from :func:`~repro.kernels.ivf_fused.kernel.
+invert_probes`, walks every list (a list no row probed merges nothing),
+and merges with the shared lexsort :func:`~repro.retrieval.topk.
+masked_topk_by_id`.  Because (score desc, id asc) is a strict total order
+the two merge formulations are equivalent, so the parity tests can demand
+exact id *and* value equality against the interpret-mode kernel.
 """
 
 from __future__ import annotations
@@ -15,8 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ivf_fused.kernel import score_block
+from repro.kernels.ivf_fused.kernel import LIST_CHUNK, ROW_TILE, score_block
 from repro.retrieval.topk import masked_topk_by_id
+from repro.utils import cdiv
 
 
 @functools.partial(jax.jit, static_argnames=("k", "backend"))
@@ -26,26 +30,34 @@ def fused_ivf_topk_ref(probes: jax.Array, qe: jax.Array,
                        ) -> tuple[jax.Array, jax.Array]:
     """Same contract as ``kernel.fused_ivf_topk_pallas`` (Q, k) outputs."""
     n_q = probes.shape[0]
+    nlist, max_len, w = list_storage.shape
+    lc = min(LIST_CHUNK, max_len)
+    n_chunks = cdiv(max_len, lc)
+    pad = ((0, cdiv(n_q, ROW_TILE) * ROW_TILE - n_q), (0, 0))
+    qe = jnp.pad(qe, pad)                    # the kernel's padded row block
+    probes = jnp.pad(probes, pad, constant_values=-1)   # pad rows probe none
+    base = jnp.pad(base.astype(jnp.float32), pad)
+    # the kernel's ragged last chunk, as rows that hold no doc
+    tail = n_chunks * lc - max_len
+    list_storage = jnp.pad(list_storage, ((0, 0), (0, tail), (0, 0)))
+    list_ids = jnp.pad(list_ids, ((0, 0), (0, tail)), constant_values=-1)
 
-    def step(carry, inp):
-        pj, bj = inp                              # (Q,) list ids, corrections
-        ids_j = list_ids[pj]                      # (Q, L)
-        blocks = list_storage[pj]                 # (Q, L, w)
-        # lax.map, not vmap: each (query, block) pair hits dot_general with
-        # the kernel's exact (1, d) × (L, d) shape, so the f32/bf16
-        # accumulation order — and hence every score bit — matches the
-        # interpret-mode kernel (vmap would batch the GEMM and reassociate)
-        s = jax.lax.map(
-            lambda qb: score_block(qb[0][None, :], qb[1], backend)[0],
-            (qe, blocks))
-        s = s + bj[:, None]
-        s = jnp.where(ids_j >= 0, s, -jnp.inf)
+    def step(carry, t):
+        c, lid = t // nlist, t % nlist
+        col = jnp.max(jnp.where(probes == lid, base, -jnp.inf), axis=1,
+                      keepdims=True)
+        block = jax.lax.dynamic_slice(list_storage, (lid, c * lc, 0),
+                                      (1, lc, w))[0]
+        ids = jax.lax.dynamic_slice(list_ids, (lid, c * lc), (1, lc))
+        s = score_block(qe, block, backend) + col
+        s = jnp.where(ids >= 0, s, -jnp.inf)
         rv, ri = carry
         cv = jnp.concatenate([rv, s], axis=1)
-        ci = jnp.concatenate([ri, jnp.where(ids_j >= 0, ids_j, -1)], axis=1)
+        ci = jnp.concatenate([ri, jnp.broadcast_to(ids, s.shape)], axis=1)
         return masked_topk_by_id(cv, ci, k), None
 
-    init = (jnp.full((n_q, k), -jnp.inf, jnp.float32),
-            jnp.full((n_q, k), -1, jnp.int32))
-    (vals, ids), _ = jax.lax.scan(step, init, (probes.T, base.T))
-    return vals, ids
+    init = (jnp.full((qe.shape[0], k), -jnp.inf, jnp.float32),
+            jnp.full((qe.shape[0], k), -1, jnp.int32))
+    (vals, ids), _ = jax.lax.scan(step, init,
+                                  jnp.arange(n_chunks * nlist))
+    return vals[:n_q], ids[:n_q]

@@ -187,6 +187,11 @@ class IVFIndex:
         self._source = None    # (CompressedIndex, version) when promoted
         self._search_fn = None
         self._list_layout = None       # lazy list-major (version, stor, ids)
+        # summed over fused-kernel launches: query rows × nprobe, what a
+        # (row, probe) grid would fetch, and the list steps of the launched
+        # grids, min(nlist, rows × nprobe) a launch, one fetch of each list
+        self.probe_pairs = 0
+        self.list_steps = 0
         self._fused_reference_only = False   # tests: force the jnp ref mirror
         self.store = None              # ListStore when tiered (storage=None)
         self._store_fns = None         # lazy (route_fn, step_fn) jit pair
@@ -353,8 +358,8 @@ class IVFIndex:
             if a is not None:
                 aux += int(a.size * a.dtype.itemsize)
         if self._list_layout is not None:
-            ls = self._list_layout[1]
-            aux += int(ls.size * ls.dtype.itemsize)
+            for a in self._list_layout[1:]:
+                aux += int(a.size * a.dtype.itemsize)
         return aux
 
     # -- search ------------------------------------------------------------
@@ -389,22 +394,27 @@ class IVFIndex:
     def _list_major_layout(self) -> tuple[jax.Array, jax.Array]:
         """(nlist, max_len, w) list-major storage + (nlist, max_len) ids.
 
-        The fused kernel DMAs whole inverted lists, so rows must be
-        contiguous per list.  Built lazily on the first fused search and
-        cached against ``_version`` (counted in :attr:`aux_nbytes`); the
-        canonical row-major ``storage`` stays the single source of truth
-        for persistence, sharding, and the jnp path.
+        The fused kernel DMAs inverted lists in chunks, so rows must be
+        contiguous per list; ``max_len`` is padded here, once, to
+        ``LIST_ALIGN`` (−1 ids), so no search relays the storage out.
+        Built lazily on the first fused search and cached against
+        ``_version`` (counted in :attr:`aux_nbytes`); the canonical
+        row-major ``storage`` stays the single source of truth for
+        persistence, sharding, and the jnp path.
         """
         if self._list_layout is not None and \
                 self._list_layout[0] == self._version:
             return self._list_layout[1], self._list_layout[2]
-        list_storage = self.storage[jnp.maximum(self.lists, 0)]
-        pad = (self.lists < 0)[..., None]
+        from repro.kernels.ivf_fused.kernel import LIST_ALIGN
+        tail = -self.lists.shape[1] % LIST_ALIGN
+        lists = jnp.pad(self.lists, ((0, 0), (0, tail)), constant_values=-1)
+        list_storage = self.storage[jnp.maximum(lists, 0)]
+        pad = (lists < 0)[..., None]
         if list_storage.ndim == 3:
             list_storage = jnp.where(pad, jnp.zeros((), list_storage.dtype),
                                      list_storage)
-        self._list_layout = (self._version, list_storage, self.lists)
-        return list_storage, self.lists
+        self._list_layout = (self._version, list_storage, lists)
+        return list_storage, lists
 
     def _streaming_search_fn(self):
         """jit'd route→scan(gather→score→merge) streaming top-k (jnp path).
@@ -650,6 +660,9 @@ class IVFIndex:
             if fused:
                 v, i = fn(qc, self.centroids, list_storage, list_ids,
                           params, k=k, nprobe=nprobe)
+                # from the launched shapes: a device read would sync here
+                self.probe_pairs += qc.shape[0] * nprobe
+                self.list_steps += min(self.nlist, qc.shape[0] * nprobe)
             else:
                 v, i = fn(qc, self.centroids, self.lists, self.storage,
                           params, k=k, nprobe=nprobe)

@@ -95,15 +95,15 @@ def merge_topk_block(run_v: jax.Array, run_i: jax.Array, cand_v: jax.Array,
     lexsort (XLA lowers that sort to a scalar comparator loop on CPU —
     ~1000× the cost of these k vectorised passes, and it has no TPU
     lowering at all; this formulation is what the fused Pallas kernel
-    runs in VMEM).  Pad entries are (−inf, −1) throughout, matching
-    ``masked_topk_by_id``'s normalisation.
+    runs in VMEM, once per (list chunk, query block) tile).  Unreachable
+    output slots are (−inf, −1), matching ``masked_topk_by_id``'s
+    normalisation, whatever id a −inf candidate carried.
 
     Requires distinct (score, id) pairs among *reachable* candidates
-    (every −inf entry is normalised to id −1, so pads are exempt): a
-    round retires every entry matching the winning pair at once.  IVF
-    candidate streams satisfy this — each doc id appears in exactly one
-    probed list and the running buffer holds previously-merged distinct
-    ids.
+    (−inf entries are exempt): a round retires every entry matching the
+    winning pair at once.  IVF candidate streams satisfy this — each doc
+    id appears in exactly one list chunk, merged once, and the running
+    buffer holds previously-merged distinct ids.
     """
     cv = jnp.concatenate([run_v, cand_v], axis=1)
     ci = jnp.concatenate([run_i, cand_i], axis=1)
@@ -133,9 +133,8 @@ def streaming_masked_topk(s: jax.Array, ids: jax.Array, k: int,
     Because (score desc, id asc) is a *strict total order*, the blockwise
     merge is associative and exact: the result is bit-identical to the
     monolithic ``masked_topk_by_id(s, ids, k)`` for **any** block size
-    (property-tested in tests/test_ivf_fused.py).  This is the schedule the
-    fused Pallas IVF kernel uses on TPU, expressed in jnp for the
-    host/reference path.
+    (property-tested in tests/test_ivf_fused.py).  The fused Pallas IVF
+    kernel streams its list chunks through the same running top-k.
     """
     n = s.shape[1]
     if block < 1:

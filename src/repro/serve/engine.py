@@ -301,12 +301,17 @@ class ServeEngine:
         host clock around ``index.search`` and the blocking copy;
         ``request_*`` keys are per-request queue-entry → last-batch-done.
         ``rows_padded`` counts the pad rows dispatched beyond the valid
-        ones."""
+        ones; ``probe_pairs`` / ``list_steps`` are the fused IVF kernel's
+        counters (:class:`~repro.retrieval.ivf.IVFIndex`), 0 for indexes
+        that do not run it."""
+        main = getattr(self.index, "main", self.index)  # SegmentedIndex
         with self._lock:
             s = {"requests_served": self.requests_served,
                  "queries_served": self.queries_served,
                  "batches_served": self.batches_served,
                  "rows_padded": self.rows_padded,
+                 "probe_pairs": getattr(main, "probe_pairs", 0),
+                 "list_steps": getattr(main, "list_steps", 0),
                  "requests_submitted": self.requests_submitted,
                  "queries_submitted": self.queries_submitted,
                  "pending_requests": len(self._pending),

@@ -13,11 +13,15 @@ from hypothesis_compat import given, settings, st
 from repro.core import (CenterNorm, CompressionPipeline, LearnedRotation,
                         OneBitQuantizer, PCA, build_method)
 from repro.data import make_dpr_like_kb
+from repro.kernels.ivf_fused import ops as fused_ops
+from repro.kernels.ivf_fused.kernel import (LIST_ALIGN, LIST_CHUNK,
+                                           invert_probes)
 from repro.retrieval import (CompressedIndex, IVFIndex, SegmentedIndex,
                              backend_tail_stages, recall_at_k)
 from repro.retrieval.kmeans import assign, assign_balanced, kmeans_fit
 from repro.retrieval.topk import (masked_topk_by_id, resolve_nprobe,
-                                  streaming_masked_topk)
+                                  similarity, streaming_masked_topk)
+from repro.serve import ServeEngine
 
 BACKENDS = tuple(backend_tail_stages())
 
@@ -52,18 +56,143 @@ def _ref_search(idx, queries, k, nprobe=None):
 # ---------------------------------------------------------------------------
 
 
+# query batches: 16 distinct rows; 4 rows repeated 4 times, so that rows
+# share every list they probe; all 32 rows, which together probe every
+# list; 13 rows, which the kernel pads to 16
+BATCHES = {"16": lambda q: q[:16], "shared": lambda q: jnp.tile(q[:4], (4, 1)),
+           "every_list": lambda q: q, "13": lambda q: q[:13]}
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
-@pytest.mark.parametrize("nprobe", [1, 5, 24])
-def test_fused_matches_reference_bitwise(kb, backend, nprobe):
+@pytest.mark.parametrize("nprobe,batch", [
+    pytest.param(1, "16", id="1"), pytest.param(5, "16", id="5"),
+    pytest.param(24, "16", id="24"),
+    pytest.param(5, "shared", id="5-shared"),
+    pytest.param(6, "every_list", id="6-every_list"),
+    pytest.param(5, "13", id="5-rows13")])
+def test_fused_matches_reference_bitwise(kb, backend, nprobe, batch):
     """The fused Pallas kernel (interpret mode on CPU) must reproduce the
     jnp reference mirror *bit-identically* — both ids and scores — for
-    every scorer backend, from a single probed list up to full probe."""
+    every scorer backend, from a single probed list up to full probe, and
+    whether the batch's rows share lists or not."""
     idx = _build_fused(kb, backend)
-    q = kb.queries[:16]
+    q = BATCHES[batch](kb.queries)
+    if batch == "every_list":
+        cs = similarity(idx.encode_queries(q), idx.centroids, idx.sim)
+        probes = np.asarray(jax.lax.top_k(cs, nprobe)[1])
+        assert np.unique(probes).size == idx.nlist
     vals_p, ids_p = idx.search(q, 10, nprobe=nprobe)
     vals_r, ids_r = _ref_search(idx, q, 10, nprobe=nprobe)
     np.testing.assert_array_equal(np.asarray(ids_p), np.asarray(ids_r))
     np.testing.assert_array_equal(np.asarray(vals_p), np.asarray(vals_r))
+
+
+def _synthetic_lists(backend, nlist, lens, max_len, d, rng):
+    """List-major storage of ``backend`` rows, ids distinct across lists
+    and −1 past each list's length, plus the backend's query params."""
+    if backend == "onebit":
+        w = d // 32
+        storage = rng.integers(0, 2**32, (nlist, max_len, w), np.uint32)
+        params = {}
+    elif backend == "int8":
+        storage = rng.integers(0, 256, (nlist, max_len, d), np.uint8)
+        params = {"scale": jnp.asarray(rng.uniform(0.01, 0.02, d),
+                                       jnp.float32),
+                  "zero": jnp.asarray(rng.standard_normal(d), jnp.float32)}
+    else:
+        dtype = np.float16 if backend == "fp16" else np.float32
+        storage = rng.standard_normal((nlist, max_len, d)).astype(dtype)
+        params = {}
+    pos = np.arange(max_len)[None, :]
+    ids = np.where(pos < np.asarray(lens)[:, None],
+                   np.arange(nlist)[:, None] * max_len + pos, -1)
+    return jnp.asarray(storage), jnp.asarray(ids, jnp.int32), params
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_fused_chunked_lists_match_reference(backend):
+    """Lists longer than one grid step are scored chunk by chunk, the last
+    chunk ragged: with a full list, an empty one, one that ends on a chunk
+    boundary, two that end inside a chunk, a row count that is no
+    multiple of 8 and a per-(row, probe) correction, the kernel still
+    matches the reference bitwise."""
+    rng = np.random.default_rng(11)
+    nlist, nprobe, n_q, d = 5, 3, 10, 64
+    max_len = 2 * LIST_CHUNK + 604
+    lens = [max_len, 0, LIST_CHUNK, 1500, 2 * LIST_CHUNK + 3]
+    storage, ids, params = _synthetic_lists(backend, nlist, lens, max_len,
+                                            d, rng)
+    q = jnp.asarray(rng.standard_normal((n_q, d)), jnp.float32)
+    probes = jnp.asarray(np.argsort(rng.random((n_q, nlist)), axis=1)
+                         [:, :nprobe], jnp.int32)
+    extra = jnp.asarray(rng.standard_normal((n_q, nprobe)), jnp.float32)
+    got = fused_ops.fused_ivf_topk(probes, q, storage, ids, 10, backend,
+                                   params=params, extra_base=extra,
+                                   interpret=True)
+    want = fused_ops.fused_ivf_topk(probes, q, storage, ids, 10, backend,
+                                    params=params, extra_base=extra,
+                                    use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert np.all(np.asarray(got[1]) >= 0)
+
+
+def test_invert_probes_step_table_and_corrections():
+    """The step table is the sorted distinct probed lists, padded to
+    min(nlist, Q·nprobe) by repeating the last; the dense corrections are
+    −inf exactly where a row did not probe a list, the row's correction
+    where it did, and −inf on every pad row."""
+    probes = jnp.asarray([[4, 1], [1, 5], [4, 5]], jnp.int32)
+    base = jnp.asarray([[0.5, -0.0], [2.0, 3.0], [-1.5, 7.25]], jnp.float32)
+    steps, n_steps, dense = invert_probes(probes, base, nlist=6, n_rows=8)
+    np.testing.assert_array_equal(np.asarray(steps), [1, 4, 5, 5, 5, 5])
+    assert np.asarray(n_steps).tolist() == [3]
+    want = np.full((8, 6), -np.inf, np.float32)
+    for i, row in enumerate(np.asarray(probes)):
+        want[i, row] = np.asarray(base)[i]
+    np.testing.assert_array_equal(np.asarray(dense), want)
+    assert np.signbit(np.asarray(dense)[0, 1])       # −0.0 kept exactly
+    # fewer probe pairs than lists: the table is Q·nprobe long, no pad
+    steps, n_steps, _ = invert_probes(jnp.asarray([[7, 2, 9]], jnp.int32),
+                                      jnp.zeros((1, 3)), nlist=10, n_rows=8)
+    np.testing.assert_array_equal(np.asarray(steps), [2, 7, 9])
+    assert np.asarray(n_steps).tolist() == [3]
+
+
+def test_list_major_layout_is_aligned_once(kb):
+    """The list-major copy pads ``max_len`` to ``LIST_ALIGN`` with −1 ids
+    (zero rows), holds each list's ids in order, and is built once per
+    index version, not per search."""
+    idx = _build_fused(kb, "int8")
+    storage, ids = idx._list_major_layout()
+    n_lists, max_len = idx.lists.shape
+    assert ids.shape == (n_lists, max_len + -max_len % LIST_ALIGN)
+    assert storage.shape[:2] == ids.shape
+    np.testing.assert_array_equal(np.asarray(ids[:, :max_len]),
+                                  np.asarray(idx.lists))
+    assert np.all(np.asarray(ids[:, max_len:]) == -1)
+    assert not np.asarray(storage)[np.asarray(ids) < 0].any()
+    idx.search(kb.queries[:4], 5, nprobe=3)
+    assert idx._list_major_layout()[1] is ids
+
+
+def test_fused_counters(kb):
+    """``probe_pairs`` sums rows × nprobe over fused launches and
+    ``list_steps`` the launched list steps, at most min(nlist, rows ×
+    nprobe) a launch; the serving engine reports both."""
+    idx = _build_fused(kb, "int8")
+    engine = ServeEngine(idx, k=5)
+    assert engine.stats()["probe_pairs"] == engine.stats()["list_steps"] == 0
+    calls = [(1, 6), (16, 3), (13, 24), (32, 6)]
+    for rows, nprobe in calls:
+        idx.search(kb.queries[:rows], 5, nprobe=nprobe)
+    stats = engine.stats()
+    assert stats["probe_pairs"] == sum(r * p for r, p in calls)
+    assert stats["list_steps"] == sum(min(idx.nlist, r * p) for r, p in calls)
+    assert stats["list_steps"] < stats["probe_pairs"]
+    # a segmented index reports its IVF main's counters
+    assert ServeEngine(SegmentedIndex(idx), k=5).stats()["probe_pairs"] == \
+        stats["probe_pairs"]
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

@@ -81,19 +81,30 @@ DEFAULT_PROFILES: dict[str, list[KernelProfile]] = {
         [(256, 102400), (256, 12800), (256, 12800)],
     )],
     "ivf_fused": [
+        # in_specs (qe, dense corrections, list chunk, id rows), out_specs
+        # (vals, ids); lists of 2048 rows are one chunk
         KernelProfile(
-            "float", {"dq": 768, "w": 768, "max_len": 2048, "k": 10,
-                      "nprobe": 8, "n_q": 64},
-            ["float32", "float32", "int32", "float32", "float32", "int32"],
-            [(64, 1, 768), (1024, 2048, 768), (1024, 1, 2048), (64, 1, 8),
-             (64, 1, 128), (64, 1, 128)],
+            "float", {"dq": 768, "w": 768, "lc": 2048, "nlist": 1024,
+                      "q_pad": 64, "id_rows": 8, "k": 10},
+            ["float32", "float32", "float32", "int32", "float32", "int32"],
+            [(64, 768), (64, 1024), (1024, 2048, 768), (1024, 2048),
+             (64, 128), (64, 128)],
         ),
         KernelProfile(
-            "onebit", {"dq": 768, "w": 24, "max_len": 2048, "k": 10,
-                       "nprobe": 8, "n_q": 64},
-            ["int8", "uint32", "int32", "float32", "float32", "int32"],
-            [(64, 1, 768), (1024, 2048, 24), (1024, 1, 2048), (64, 1, 8),
-             (64, 1, 128), (64, 1, 128)],
+            "onebit", {"dq": 768, "w": 24, "lc": 2048, "nlist": 1024,
+                       "q_pad": 64, "id_rows": 8, "k": 10},
+            ["int8", "float32", "uint32", "int32", "float32", "int32"],
+            [(64, 768), (64, 1024), (1024, 2048, 24), (1024, 2048),
+             (64, 128), (64, 128)],
+        ),
+        # the paper's IVF 200/100 at 2.1M docs: lists of 13,248 rows laid out
+        # to 13,312, 2,048 a step
+        KernelProfile(
+            "int8", {"dq": 128, "w": 128, "lc": 2048, "nlist": 200,
+                     "q_pad": 64, "id_rows": 8, "k": 10},
+            ["bfloat16", "float32", "uint8", "int32", "float32", "int32"],
+            [(64, 128), (64, 200), (200, 13312, 128), (200, 13312),
+             (64, 128), (64, 128)],
         ),
     ],
 }
