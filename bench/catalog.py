@@ -9,7 +9,12 @@ and entries and edits none:
 * ``bench/traffic/<traffic>.json`` — the traffic mix (JSON);
 * ``bench/metrics/<metric>.py`` — the reader of one metric: a function
   ``read(run)`` that returns a number, or ``None`` where the run holds
-  nothing for it to read.
+  nothing for it to read;
+* ``bench/references/<recipe>.py`` — a plain reference recipe, named by a
+  configuration's ``reference.recipe``: ``build(corpus, sample, **params)``
+  and ``control(corpus, sample, **params)``, each returning an object with
+  ``n_docs``, ``scores_of(queries, ids)`` and ``topk(queries, k)`` (the
+  control is the recipe one step coarser).
 """
 
 from __future__ import annotations
@@ -18,6 +23,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Optional
 
 from bench.loadgen import Traffic
@@ -65,17 +71,36 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                            if _reports(m, name)])
 
 
+def _load(path: Path, kind: str, name: str) -> ModuleType:
+    """The module at ``path`` (a name may hold dots, so the file is loaded
+    by path, not imported by module name)."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def metric_reader(name: str, root: Path = ROOT) -> Callable:
-    """``read`` of ``bench/metrics/<name>.py`` (a name may hold dots, so
-    the file is loaded by path, not imported by module name)."""
+    """``read`` of ``bench/metrics/<name>.py``."""
     path = root / "bench" / "metrics" / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load(path, "metric", name).read
+
+
+def reference_recipe(name: Optional[str], root: Path = ROOT) -> ModuleType:
+    """The recipe module ``bench/references/<name>.py``.  A configuration
+    that names no recipe, or one with no file, is an error: there is no
+    default."""
+    folder = root / "bench" / "references"
+    path = folder / f"{name}.py"
+    if not name or not path.is_file():
+        have = sorted(p.name for p in folder.glob("*.py"))
+        raise FileNotFoundError(
+            f"reference recipe {name!r} has no file in {folder}; the "
+            f"configuration's reference.recipe names one of {have}")
+    return _load(path, "reference", name)
 
 
 def read_metrics(entries: list, run, root: Path = ROOT

@@ -1,7 +1,8 @@
 """The output check: what the window served against the plain reference.
 
 A sample of the window's requests, drawn from the seed, is compared row by
-row with :class:`bench.reference.Reference`.  The numbers, each held to the
+row with the plain reference that the configuration's ``reference.recipe``
+names (``bench/references/<recipe>.py``).  The numbers, each held to the
 limit that its configuration file gives:
 
 ``lost``

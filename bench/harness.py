@@ -113,10 +113,6 @@ def require_chips(chips: int):
     return devices
 
 
-def _engine(service):
-    return service._registry.get(INDEX_NAME).live_version().engine
-
-
 def _counters(engine) -> dict:
     s = engine.stats()
     return {"queries_served": s["queries_served"],
@@ -275,7 +271,7 @@ class Session:
         self.service = RetrievalService(default_k=traffic.k)
         try:
             self.service.register(INDEX_NAME, artifact=str(self.artifact))
-            self.engine = _engine(self.service)
+            self.engine = self.service.engine(INDEX_NAME)
             run.max_batch = int(self.engine.batcher.max_batch)
             run.setup["register"] = time.perf_counter() - t
             log(f"setup register {run.setup['register']:.3f} s")
@@ -368,11 +364,25 @@ class Session:
         return (q, np.concatenate([r.ids for r in picked]),
                 np.concatenate([r.scores for r in picked]))
 
-    def reference(self, levels: Optional[int] = None):
-        from bench.reference import Reference
-        ref = self.cell.config["reference"]
-        return Reference(self.corpus, self.fit_queries, dim=ref["dim"],
-                         levels=levels or ref["levels"])
+    def _recipe(self):
+        """The configuration's reference recipe and its parameters."""
+        params = dict(self.cell.config["reference"])
+        recipe = catalog.reference_recipe(params.pop("recipe", None),
+                                          self.root)
+        return recipe, params
+
+    def reference(self):
+        """The plain reference that decides ``correct``: the recipe that
+        the configuration's ``reference`` names, built from the seed's
+        corpus after the program's state is freed."""
+        recipe, params = self._recipe()
+        return recipe.build(self.corpus, self.fit_queries, **params)
+
+    def control(self):
+        """The recipe one step coarser, for the program's place in the
+        readings and the tests; the benchmark's own runs never build it."""
+        recipe, params = self._recipe()
+        return recipe.control(self.corpus, self.fit_queries, **params)
 
 
 def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,

@@ -8,10 +8,10 @@ it), a short window of each cell's own traffic through the served path, and
 the check's numbers of what it served (the lower readings).  On the first
 ``--control-seeds`` seeds also:
 
-* the control: the reference computed one precision step below the
-  configuration's (int4 codes for its int8), put in the program's place —
-  it answers the same sampled rows, and its answers are compared with the
-  int8 reference like the program's;
+* the control: the configuration's reference recipe one step coarser
+  (its ``control``: int4 codes for ``pca_affine``'s int8), put in the
+  program's place — it answers the same sampled rows, and its answers are
+  compared with the reference like the program's;
 * for an IVF configuration, a routing fault planted in the program: the
   centroids permuted, so that every query probes the wrong lists.
 
@@ -33,12 +33,11 @@ from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-CONTROL_LEVELS = 15          # int4: one precision step below int8
 
 
 def control_numbers(ref, ref_low, q, k, names) -> dict:
-    """The control's numbers: the lower-precision reference answers ``q``
-    and is compared with the configuration's reference."""
+    """The control's numbers: the coarser reference answers ``q`` and is
+    compared with the configuration's reference."""
     from bench import check
     scores, ids = ref_low.topk(q, k)
     return check.compare(q, ids, scores, ref, k, names)
@@ -132,8 +131,7 @@ def main(argv=None) -> int:
         finally:
             session.close()
         ref = session.reference()
-        low = (session.reference(levels=CONTROL_LEVELS)
-               if i < args.control_seeds else None)
+        low = session.control() if i < args.control_seeds else None
         for cell in cells:
             q, ids, scores, lost = served[cell.name]
             k = cell.traffic.k

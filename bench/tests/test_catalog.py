@@ -24,7 +24,8 @@ def root(tmp_path):
     _write(tmp_path / "bench" / "configs" / "tiny.json", json.dumps({
         "name": "tiny", "corpus": {"n_docs": 64}, "fit_sample": 8,
         "index": {"method": "pca_int8", "dim": 16, "post": False},
-        "reference": {"dim": 16, "levels": 255},
+        "reference": {"recipe": "pca_affine", "dim": 16,
+                      "levels": 255},
         "check": {"lost": 0, "score_err": 0.5}}))
     _write(tmp_path / "bench" / "traffic" / "burst.8.json", json.dumps({
         "loop": "open", "rows": 8, "k": 5, "pool": 32, "rate": 7.0}))
